@@ -52,12 +52,7 @@ def one_layer_flops(arch: str, layer_idx: int):
         return y
 
     compiled = jax.jit(f).lower(params, x).compile()
-    # cost_analysis() returns a dict in newer jax, a one-element list of
-    # dicts in older releases.
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    hlo = float((ca or {}).get("flops", 0.0))
+    hlo = float((compiled.cost_analysis() or {}).get("flops", 0.0))
 
     flags = analytic.ExecFlags(chunk_len=S)
     ana = analytic._mixer_flops(cfg, kind, B, S, S, flags, useful=False)

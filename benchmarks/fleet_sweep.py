@@ -39,6 +39,7 @@ import numpy as np
 from repro.core import fleet, perfmodel, profiler
 from repro.core.timing import PARAM_NAMES
 from repro.kernels.charge_sweep import ops as charge_sweep
+from repro.launch.compile_cache import enable_compile_cache
 
 try:
     from benchmarks._json_out import write_rows_json
@@ -316,6 +317,7 @@ def main() -> None:
                          "path (BENCH_region_sweep.json); requires --regions")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.bench_json and not args.regions:
         ap.error("--bench-json records the region sweep; add --regions N")

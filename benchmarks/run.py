@@ -10,12 +10,15 @@ import argparse
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--skip-altune", action="store_true",
                     help="skip interpret-mode kernel profiling (slow)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks import (
         crossval,
@@ -39,11 +42,6 @@ def main() -> None:
         ("roofline (dry-run cells)", roofline.run),
         ("analytic-vs-HLO crossval", crossval.run),
     ]
-    try:
-        from benchmarks import steptuner_bench
-        sections.append(("step auto-tuner (train cells)", steptuner_bench.run))
-    except Exception:  # needs 512 host devices; skip under other envs
-        pass
     if not args.skip_altune:
         from benchmarks import altune_margin
         sections.append(("altune margin (TPU embodiment)", altune_margin.run))

@@ -61,6 +61,7 @@ import numpy as np
 from repro import analysis
 from repro.core import controller, fleet, perfmodel, stream, traces
 from repro.kernels.replay_step import default_interpret
+from repro.launch.compile_cache import enable_compile_cache
 
 try:
     from benchmarks._json_out import write_bench_replay_json, write_rows_json
@@ -508,6 +509,7 @@ def main() -> None:
                          "DIMM-steps/sec, peak-memory estimate)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     sweep = tuple(
         int(c) for c in args.chunk_sweep.split(",")
     ) if args.chunk_sweep else ()
@@ -543,6 +545,8 @@ def main() -> None:
         write_rows_json(args.json, "stream_replay", rows, meta=meta)
     if args.bench_json:
         bench_cfg["device"] = jax.devices()[0].platform
+        bench_cfg["device_kind"] = jax.devices()[0].device_kind
+        bench_cfg["device_count"] = jax.device_count()
         write_bench_replay_json(args.bench_json, bench_cfg, bench, meta=meta)
 
 

@@ -76,6 +76,7 @@ import numpy as np
 
 from repro.core import controller, fleet, perfmodel, traces
 from repro.core import refresh as rf
+from repro.launch.compile_cache import enable_compile_cache
 
 try:
     from benchmarks._json_out import write_rows_json
@@ -486,6 +487,7 @@ def main() -> None:
                          "comparison rows to this path (BENCH_trace_eval.json)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.tiny:
         conflicts = [name for name, val in (

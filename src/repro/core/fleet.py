@@ -499,9 +499,6 @@ def _sweep_grid_pallas_regions(
 def _sharded_region_sweep_runner(
     mesh,
     n_dimms: int,
-    temps: Tuple[float, ...],
-    patterns: Tuple[float, ...],
-    n_regions: int,
     window_s: float,
     consts: ChargeModelConstants,
     write_tras: str,
@@ -510,23 +507,22 @@ def _sharded_region_sweep_runner(
 ):
     """Cached (pad → shard_map → slice) wrapper for one region-sweep
     configuration; the DIMM axis sits at position 3 of the (T, P, R, N, 4)
-    stacks."""
-    t = jnp.asarray(temps, jnp.float32)
-    p = jnp.asarray(patterns, jnp.float32)
-    f = charge.region_fracs(n_regions)
+    stacks. Called as ``run(cells, temps, patterns, region_fracs)``, the
+    grid replicated, for the reason :func:`_sharded_sweep_runner` gives."""
     if impl == "pallas":
 
-        def grid_fn(c: CellParams):
+        def grid_fn(c: CellParams, t: Array, p: Array, f: Array):
             return _sweep_grid_pallas_regions(
                 c, t, p, f, window_s, consts, write_tras, interpret
             )
     else:
 
-        def grid_fn(c: CellParams):
+        def grid_fn(c: CellParams, t: Array, p: Array, f: Array):
             return _sweep_grid_regions(c, t, p, f, window_s, consts, write_tras)
 
     return shard.sharded_dimm_map(
-        grid_fn, mesh, in_axes=(0,), out_axes=(3, 3), n_dimms=n_dimms
+        grid_fn, mesh, in_axes=(0, None, None, None), out_axes=(3, 3),
+        n_dimms=n_dimms,
     )
 
 
@@ -563,11 +559,9 @@ def sweep_regions(
             f"impl must be one of {charge_sweep.IMPLS}, got {impl!r}"
         )
     cells = fleet.cells if isinstance(fleet, Fleet) else fleet
-    temps_key = tuple(float(x) for x in temps_c)
-    patterns_key = tuple(float(x) for x in patterns)
     interp = charge_sweep.default_interpret() if interpret is None else interpret
-    t = jnp.asarray(temps_key, jnp.float32)
-    p = jnp.asarray(patterns_key, jnp.float32)
+    t = jnp.asarray([float(x) for x in temps_c], jnp.float32)
+    p = jnp.asarray([float(x) for x in patterns], jnp.float32)
     f = charge.region_fracs(int(n_regions))
     if mesh is None:
         if impl == "pallas":
@@ -580,10 +574,10 @@ def sweep_regions(
             )
     else:
         run = _sharded_region_sweep_runner(
-            mesh, int(cells.r.shape[0]), temps_key, patterns_key,
-            int(n_regions), float(window_s), consts, write_tras, impl, interp,
+            mesh, int(cells.r.shape[0]), float(window_s), consts,
+            write_tras, impl, interp,
         )
-        read, write = run(cells)
+        read, write = run(cells, t, p, f)
     return RegionSweepResult(
         temps_c=t, patterns=p, region_fracs=f, read=read, write=write,
         temps_exact=tuple(float(x) for x in temps_c),
@@ -594,8 +588,6 @@ def sweep_regions(
 def _sharded_sweep_runner(
     mesh,
     n_dimms: int,
-    temps: Tuple[float, ...],
-    patterns: Tuple[float, ...],
     window_s: float,
     consts: ChargeModelConstants,
     write_tras: str,
@@ -603,23 +595,29 @@ def _sharded_sweep_runner(
     interpret: bool,
 ):
     """Cached (pad → shard_map → slice) wrapper for one sweep
-    configuration: repeated sharded sweeps of the same (mesh, fleet size,
-    grid) hit the jit cache instead of re-tracing the whole study."""
-    t = jnp.asarray(temps, jnp.float32)
-    p = jnp.asarray(patterns, jnp.float32)
+    configuration: repeated sharded sweeps of the same (mesh, fleet size)
+    hit the jit cache instead of re-tracing the whole study.
+
+    Called as ``run(cells, temps, patterns)``: the grid rides in as
+    replicated arguments, exactly as in the unsharded call. Closed over,
+    it would be a compile-time constant, and XLA would fold the
+    temperature-dependent charge-model terms on the host, whose ``exp``
+    is not the device's; on a TPU that moves the min-safe index of a cell
+    that sits at a grid point's ``_EPS`` boundary."""
     if impl == "pallas":
 
-        def grid_fn(c: CellParams):
+        def grid_fn(c: CellParams, t: Array, p: Array):
             return _sweep_grid_pallas(
                 c, t, p, window_s, consts, write_tras, interpret
             )
     else:
 
-        def grid_fn(c: CellParams):
+        def grid_fn(c: CellParams, t: Array, p: Array):
             return _sweep_grid(c, t, p, window_s, consts, write_tras)
 
     return shard.sharded_dimm_map(
-        grid_fn, mesh, in_axes=(0,), out_axes=(2, 2, 2), n_dimms=n_dimms
+        grid_fn, mesh, in_axes=(0, None, None), out_axes=(2, 2, 2),
+        n_dimms=n_dimms,
     )
 
 
@@ -679,11 +677,9 @@ def sweep(
             f"impl must be one of {charge_sweep.IMPLS}, got {impl!r}"
         )
     cells = fleet.cells if isinstance(fleet, Fleet) else fleet
-    temps_key = tuple(float(x) for x in temps_c)
-    patterns_key = tuple(float(x) for x in patterns)
     interp = charge_sweep.default_interpret() if interpret is None else interpret
-    t = jnp.asarray(temps_key, jnp.float32)
-    p = jnp.asarray(patterns_key, jnp.float32)
+    t = jnp.asarray([float(x) for x in temps_c], jnp.float32)
+    p = jnp.asarray([float(x) for x in patterns], jnp.float32)
     if mesh is None:
         if impl == "pallas":
             read, write, joint = _sweep_grid_pallas(
@@ -695,10 +691,10 @@ def sweep(
             )
     else:
         run = _sharded_sweep_runner(
-            mesh, int(cells.r.shape[0]), temps_key, patterns_key,
-            float(window_s), consts, write_tras, impl, interp,
+            mesh, int(cells.r.shape[0]), float(window_s), consts,
+            write_tras, impl, interp,
         )
-        read, write, joint = run(cells)
+        read, write, joint = run(cells, t, p)
     return SweepResult(
         temps_c=t, patterns=p, read=read, write=write, joint=joint,
         temps_exact=tuple(float(x) for x in temps_c),

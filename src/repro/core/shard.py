@@ -65,23 +65,6 @@ from repro.launch.mesh import fleet_mesh  # noqa: F401  (the mesh builder)
 #: the pipeline expects a mesh carrying this axis.
 DIMM_AXIS: str = "dimm"
 
-try:  # jax >= 0.6: public jax.shard_map (replication check renamed)
-    from jax import shard_map as _shard_map_impl
-
-    def _shard_map(fn, mesh, in_specs, out_specs):
-        return _shard_map_impl(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-except ImportError:  # jax < 0.6: experimental API, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map(fn, mesh, in_specs, out_specs):
-        return _shard_map_impl(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-
 
 def n_shards(mesh: Mesh) -> int:
     """Size of the mesh's DIMM axis (raises if the axis is absent)."""
@@ -194,10 +177,11 @@ def sharded_dimm_map(
     target = padded_size(n_dimms, shards)
     in_axes = tuple(in_axes)
     out_axes = tuple(out_axes)
-    mapped = jax.jit(_shard_map(
-        fn, mesh,
-        tuple(_spec(a) for a in in_axes),
-        tuple(_spec(a) for a in out_axes),
+    mapped = jax.jit(jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(_spec(a) for a in in_axes),
+        out_specs=tuple(_spec(a) for a in out_axes),
+        check_vma=False,
     ))
 
     def run(*args):

@@ -28,7 +28,8 @@ lives anyway; the exp itself is a single VPU op.
 Layout: cells (any (DIMM × temperature × pattern) tile, flattened by
 :mod:`.ops`) ride the VPU lanes as (8, 128) f32 tiles; the grid walks cell
 tiles; the timing grid is a ``fori_loop`` carrying 7 × (index, found)
-running reductions in registers. Inputs arrive as ONE stacked
+running reductions in registers (found as int32 0/1: Mosaic refuses a
+boolean vector loop carry). Inputs arrive as ONE stacked
 (N_INVARIANTS, 8, 128) block per tile; outputs leave as one
 (N_SEARCHES, 8, 128) int32 block of min-safe indices.
 """
@@ -139,14 +140,15 @@ def _charge_sweep_kernel(
         new_idxs, new_founds = [], []
         for j in range(N_SEARCHES):
             ok = passes[j] & masks[j] & (k < n_grid[j])
-            new_idxs.append(jnp.where(ok & ~founds[j], k, idxs[j]))
-            new_founds.append(founds[j] | ok)
+            found = founds[j] > 0
+            new_idxs.append(jnp.where(ok & ~found, k, idxs[j]))
+            new_founds.append((found | ok).astype(jnp.int32))
         return tuple(new_idxs), tuple(new_founds)
 
     init = (
         # All-False searches keep the last grid index — the JEDEC pin.
         tuple(jnp.full(TILE, n - 1, jnp.int32) for n in n_grid),
-        tuple(jnp.zeros(TILE, jnp.bool_) for _ in n_grid),
+        tuple(jnp.zeros(TILE, jnp.int32) for _ in n_grid),
     )
     idxs, _ = jax.lax.fori_loop(0, max_n, candidate, init)
     for j in range(N_SEARCHES):

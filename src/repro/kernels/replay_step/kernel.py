@@ -45,13 +45,18 @@ state as (3, 8, 128) int32 [bin, streak, fused], occupancy as
 8 slots. The step axis walks a ``fori_loop`` whose carry is the full
 register set; the grid walks DIMM tiles.
 
-Tile-size guidance: the resident per-tile working set is
-``(n_bins·8 + chunk·2 + n_bins + 14) · 4 KiB`` (stack + telemetry +
-accumulators per 1024-DIMM tile) — at 5 bins and chunk 256 that is
-~2.3 MiB, comfortably inside a TensorCore's ~16 MiB VMEM. On real TPU,
-sweep ``chunk`` (the step depth per kernel launch) via
-``benchmarks/stream_replay.py --chunk-sweep`` rather than the lane tile:
-(8, 128) is the f32 VPU register shape and should stay fixed.
+VMEM budget: every block is a whole number of 4 KiB (8, 128) tiles, and
+the Pallas pipeline double-buffers each input and output block. The
+chunk kernel's blocks per 1024-DIMM tile are ``3 + (n_bins+1) + 1 + 8 +
+8·n_bins + 2·chunk`` in and ``3 + (n_bins+1) + 1 + 8`` out, so it holds
+``2 · (10·n_bins + 2·chunk + 26) · 4 KiB`` — 4.6 MiB at 5 bins and
+chunk 256, inside the 16 MiB scoped VMEM limit (the telemetry block
+grows with ``chunk`` and reaches the limit near chunk 980). The accumulate kernel
+streams its decision block :data:`ACC_STEP_BLOCK` steps at a time, so it
+holds ``2 · (10·steps + 2·(n_bins+1) + 18) · 4 KiB`` — 2.7 MiB at 32
+steps and 5 bins, whatever the chunk length. Taking the whole chunk as
+one block would need 20.2 MiB at chunk 256. (8, 128) is the f32 VPU
+register shape and stays fixed.
 """
 
 from __future__ import annotations
@@ -63,6 +68,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: Flattened (access, param) slots per timing row: 2 access types × 4
 #: timing parameters, slot index ``a * 4 + p``.
@@ -115,12 +121,14 @@ def _replay_chunk_kernel(
     rows = tuple(stack_ref[i] for i in range(n_bins * ROW_SLOTS))
 
     def one_step(k, carry):
-        bin_idx, streak, fused, sw, occ, sums = carry
+        # ``fused`` is carried as int32 0/1: Mosaic refuses a boolean
+        # vector loop carry.
+        bin_idx, streak, fused_i, sw, occ, sums = carry
         temp = temps_ref[k]
         err = errs_ref[k] > 0.5
 
         # --- controller transition (mirrors controller._advance_dimm) ---
-        fused = fused | err
+        fused = (fused_i > 0) | err
         t_eff = temp + guard
         target = jnp.zeros(TILE, jnp.int32)
         for e in edges:
@@ -156,12 +164,13 @@ def _replay_chunk_kernel(
             for b in range(n_bins - 1, -1, -1):
                 row_j = jnp.where(eff_bin == b, rows[b * ROW_SLOTS + j], row_j)
             new_sums.append(sums[j] + row_j)
-        return new_bin, new_streak, fused, sw, occ, tuple(new_sums)
+        return (new_bin, new_streak, fused.astype(jnp.int32), sw, occ,
+                tuple(new_sums))
 
     init = (
         state_ref[0],
         state_ref[1],
-        state_ref[2] > 0,
+        state_ref[2],
         sw_ref[...],
         tuple(occ_ref[b] for b in range(n_bins + 1)),
         tuple(sums_ref[j] for j in range(ROW_SLOTS)),
@@ -171,7 +180,7 @@ def _replay_chunk_kernel(
     )
     state_out[0] = bin_idx
     state_out[1] = streak
-    state_out[2] = fused.astype(jnp.int32)
+    state_out[2] = fused
     for b in range(n_bins + 1):
         occ_out[b] = occ[b]
     sw_out[...] = sw
@@ -237,23 +246,45 @@ def replay_chunk_tiled(
     )(state3, occ, sw, sums, stack, temps, errs)
 
 
+#: Steps per accumulate-kernel block. The decision block streams through
+#: VMEM ``ACC_STEP_BLOCK`` steps at a time (grid axis 1) while the running
+#: partials stay resident, so the kernel's VMEM use does not grow with the
+#: chunk length.
+ACC_STEP_BLOCK: int = 32
+
+
+def _step_block(chunk: int) -> int:
+    """Largest divisor of ``chunk`` that is at most :data:`ACC_STEP_BLOCK`."""
+    return max(d for d in range(1, min(chunk, ACC_STEP_BLOCK) + 1)
+               if chunk % d == 0)
+
+
 def _accumulate_kernel(
-    bins_ref,    # (chunk, 8, 128) i32 effective bins
-    swd_ref,     # (chunk, 8, 128) i32 switch flags
-    tim_ref,     # (chunk · ROW_SLOTS, 8, 128) f32 realized rows
+    bins_ref,    # (steps, 8, 128) i32 effective bins
+    swd_ref,     # (steps, 8, 128) i32 switch flags
+    tim_ref,     # (steps · ROW_SLOTS, 8, 128) f32 realized rows
     occ_ref,     # (n_bins1, 8, 128) i32 running occupancy
     sw_ref,      # (8, 128) i32 running switches
     sums_ref,    # (ROW_SLOTS, 8, 128) f32 running sums
     occ_out, sw_out, sums_out,
     *,
-    chunk: int,
+    steps: int,
     n_bins1: int,
 ):
-    """Fused ``trace_score_accumulate`` over a materialized decision block:
-    one pass folding bins/switches/timings into the running partials.
-    int accumulators are exact; the f32 timing sums match the ref's
-    ``timings.sum(axis=0)`` under the cycle-quantization envelope that
-    already makes chunked accumulation exact (see ScorePartials)."""
+    """Fused ``trace_score_accumulate`` over one step block of a
+    materialized decision block, folding bins/switches/timings into the
+    running partials. The outputs stay resident across the step-block
+    grid axis and carry the partials from one block to the next, so every
+    slot still takes one f32 add per step in step order. int accumulators
+    are exact; the f32 timing sums match the ref's ``timings.sum(axis=0)``
+    under the cycle-quantization envelope that already makes chunked
+    accumulation exact (see ScorePartials)."""
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        occ_out[...] = occ_ref[...]
+        sw_out[...] = sw_ref[...]
+        sums_out[...] = sums_ref[...]
 
     def one_step(k, carry):
         sw, occ, sums = carry
@@ -268,11 +299,11 @@ def _accumulate_kernel(
         return sw, occ, sums
 
     init = (
-        sw_ref[...],
-        tuple(occ_ref[i] for i in range(n_bins1)),
-        tuple(sums_ref[j] for j in range(ROW_SLOTS)),
+        sw_out[...],
+        tuple(occ_out[i] for i in range(n_bins1)),
+        tuple(sums_out[j] for j in range(ROW_SLOTS)),
     )
-    sw, occ, sums = jax.lax.fori_loop(0, chunk, one_step, init)
+    sw, occ, sums = jax.lax.fori_loop(0, steps, one_step, init)
     for i in range(n_bins1):
         occ_out[i] = occ[i]
     sw_out[...] = sw
@@ -291,24 +322,29 @@ def accumulate_tiled(
     interpret: bool = False,
 ):
     """Fused partials accumulation over tiled decision blocks; returns
-    ``(occ, sw, sums)`` with input shapes/dtypes."""
+    ``(occ, sw, sums)`` with input shapes/dtypes. The grid walks DIMM
+    tiles (axis 0) and, within each tile, step blocks in order (axis 1)."""
     chunk = bins.shape[0]
     n_bins1 = occ.shape[0]
     rows_, lanes = sw.shape
     assert lanes == TILE[1] and rows_ % TILE[0] == 0, sw.shape
     assert tim.shape == (chunk * ROW_SLOTS, rows_, lanes), tim.shape
+    steps = _step_block(chunk)
 
     def stacked_spec(n):
-        return pl.BlockSpec((n, TILE[0], TILE[1]), lambda i: (0, i, 0))
+        return pl.BlockSpec((n, TILE[0], TILE[1]), lambda i, k: (0, i, 0))
 
-    flat_spec = pl.BlockSpec((TILE[0], TILE[1]), lambda i: (i, 0))
+    def step_spec(n):
+        return pl.BlockSpec((n, TILE[0], TILE[1]), lambda i, k: (k, i, 0))
+
+    flat_spec = pl.BlockSpec((TILE[0], TILE[1]), lambda i, k: (i, 0))
     return pl.pallas_call(
-        functools.partial(_accumulate_kernel, chunk=chunk, n_bins1=n_bins1),
-        grid=(rows_ // TILE[0],),
+        functools.partial(_accumulate_kernel, steps=steps, n_bins1=n_bins1),
+        grid=(rows_ // TILE[0], chunk // steps),
         in_specs=[
-            stacked_spec(chunk),
-            stacked_spec(chunk),
-            stacked_spec(chunk * ROW_SLOTS),
+            step_spec(steps),
+            step_spec(steps),
+            step_spec(steps * ROW_SLOTS),
             stacked_spec(n_bins1),
             flat_spec,
             stacked_spec(ROW_SLOTS),
@@ -322,6 +358,9 @@ def accumulate_tiled(
             jax.ShapeDtypeStruct((n_bins1, rows_, lanes), jnp.int32),
             jax.ShapeDtypeStruct((rows_, lanes), jnp.int32),
             jax.ShapeDtypeStruct((ROW_SLOTS, rows_, lanes), jnp.float32),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(bins, swd, tim, occ, sw, sums)

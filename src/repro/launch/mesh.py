@@ -10,22 +10,14 @@ it (DESIGN.md §5).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 requires explicit Auto axis types for with-sharding use
-    from jax.sharding import AxisType
-
-    def _axis_kwargs(n_axes: int):
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # jax < 0.5: every mesh axis is Auto, no kwarg exists
-    AxisType = None
-
-    def _axis_kwargs(n_axes: int):
-        return {}
+from jax.sharding import AxisType
 
 
 def auto_mesh(shape, axes):
-    """``jax.make_mesh`` with all-Auto axis types on any jax version."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_kwargs(len(axes)))
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

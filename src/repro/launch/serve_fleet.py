@@ -35,6 +35,7 @@ import numpy as np
 from repro.core import fleet, stream, traces
 from repro.core.controller import ControllerParams, DimmTimingTable
 from repro.core.timing import ACCESS_TYPES, PARAM_NAMES
+from repro.launch.compile_cache import enable_compile_cache
 
 #: Bins profiled by the demo bootstrap (the paper's evaluation points).
 DEFAULT_TEMP_BINS = (45.0, 55.0, 70.0, 85.0)
@@ -50,7 +51,8 @@ class FleetControllerService:
     the JEDEC fallback sentinel) and the switch flags, which is exactly
     what a hardware-programming agent consumes. :meth:`running_score`
     finalizes the accumulated partials at any time without disturbing the
-    stream."""
+    stream. ``interpret`` passes through to the fused kernel
+    (``None``: interpret mode everywhere but TPU)."""
 
     def __init__(
         self,
@@ -58,9 +60,10 @@ class FleetControllerService:
         params: ControllerParams = ControllerParams(),
         mesh=None,
         impl: str = "ref",
+        interpret: Optional[bool] = None,
     ):
         self.engine = stream.StreamingController(
-            table, params=params, mesh=mesh, impl=impl
+            table, params=params, mesh=mesh, impl=impl, interpret=interpret
         )
 
     @property
@@ -184,6 +187,7 @@ def main() -> None:
                     help="chunk-scan implementation (pallas = fused kernel)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     serve(
         n_dimms=args.n_dimms, n_steps=args.n_steps, chunk=args.chunk,
         scenario=args.scenario, error_rate=args.error_rate,

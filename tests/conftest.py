@@ -9,22 +9,19 @@ unaffected. Unset JAX_DISABLE_MOST_OPTIMIZATIONS to measure real codegen.
 
 Persistent compilation cache: the suite is compile-bound (every property
 test traces dozens of (shape, chunk) program variants), so re-running it
-recompiles identical XLA programs from scratch. Setting
-``REPRO_JAX_CACHE_DIR=<dir>`` turns on jax's persistent compilation cache
-rooted there, with the thresholds zeroed so every program is cached (the
-defaults skip sub-second compiles — which is ALL of them on these tiny
-test shapes). CI points it at an actions/cache-restored directory; local
-example::
+recompiles identical XLA programs from scratch. JAX's own environment
+variables turn on its persistent cache; zero the thresholds so that the
+sub-second compiles of these tiny test shapes are cached too. CI points
+the directory at an actions/cache-restored path; local example::
 
-    REPRO_JAX_CACHE_DIR=~/.cache/repro-jax PYTHONPATH=src pytest -x -q
+    JAX_COMPILATION_CACHE_DIR=~/.cache/repro-jax \
+    JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0 \
+    JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES=-1 \
+    PYTHONPATH=src pytest -x -q
 
-Measured on the full tier-1 suite (CPU, one container): cold 348 s
-(populating ~8.5k cache entries), warm re-run 216 s — a 38 % cut, the
-XLA backend-compile share of the wall clock; tracing, which the cache
-cannot skip, is most of the rest.
-All three knobs are env vars (not jax.config calls) so they bind before
-any test module imports jax, and ``setdefault`` keeps explicit caller
-overrides winning.
+Measured on the full tier-1 suite (CPU run, one container): cold 348 s
+(populating ~8.5k cache entries), warm re-run 216 s. The cache skips the
+XLA backend compile; tracing, which it cannot skip, is most of the rest.
 """
 
 import os
@@ -32,12 +29,6 @@ import os
 import pytest
 
 os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
-
-_cache_dir = os.environ.get("REPRO_JAX_CACHE_DIR")
-if _cache_dir:
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", _cache_dir)
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
 
 
 @pytest.fixture(autouse=True)
