@@ -234,7 +234,12 @@ def evaluate(
     in an in-flight REFRESH waits ``tRFC / 2`` on average). At the
     defaults (0.0) every term reduces to exactly the refresh-free
     arithmetic (``x + 0`` and ``x / 1`` are exact in float32), so
-    refresh-free callers are numerically unchanged."""
+    refresh-free callers are numerically unchanged.
+
+    An occupancy given as a JAX array (the refresh-aware fleet path) runs
+    the bisection as a ``fori_loop``; a Python scalar keeps it unrolled.
+    The steps are the same operations in the same order either way (see
+    ``_ipc_stack_refresh`` for why)."""
     f = _fields(workloads)
     # Expected refresh blocking per request: P(arrive during refresh) ×
     # mean residual refresh time. The SAME absolute penalty lands on
@@ -260,14 +265,19 @@ def evaluate(
         return cfg.cpi_exe + f["mpki"] * 1e-3 * (lat + queue) * ghz / f["mlp"]
 
     # Bisection on the unique fixed point ipc = 1/cpi(ipc).
-    lo = jnp.full_like(lat, 1e-4)
-    hi = jnp.full_like(lat, 1.0 / cfg.cpi_exe)
-    for _ in range(cfg.bisect_iters):
+    def halve(_, bounds: Tuple[Array, Array]) -> Tuple[Array, Array]:
+        lo, hi = bounds
         mid = 0.5 * (lo + hi)
         go_up = 1.0 / cpi_of(mid) > mid
-        lo = jnp.where(go_up, mid, lo)
-        hi = jnp.where(go_up, hi, mid)
-    ipc = 0.5 * (lo + hi)
+        return jnp.where(go_up, mid, lo), jnp.where(go_up, hi, mid)
+
+    bounds = (jnp.full_like(lat, 1e-4), jnp.full_like(lat, 1.0 / cfg.cpi_exe))
+    if isinstance(refresh_occ, jax.Array):
+        bounds = jax.lax.fori_loop(0, cfg.bisect_iters, halve, bounds)
+    else:
+        for i in range(cfg.bisect_iters):
+            bounds = halve(i, bounds)
+    ipc = 0.5 * (bounds[0] + bounds[1])
     return {"ipc": ipc, "latency_ns": lat, "service_ns": svc}
 
 
@@ -352,6 +362,14 @@ def _ipc_stack(flat: Array, cfg: SystemConfig, workloads: Tuple[Workload, ...]) 
 # `_ipc_stack` keeps its exact operand signature (and therefore its
 # compiled program), preserving the "identical compiled programs" bitwise
 # guarantees of the refresh-free score paths.
+#
+# Its bisection is rolled, since `o` is an array. Traced into one program
+# with the JEDEC baseline's (the sharded finalize runs `_score_figures`
+# under shard_map), the two same-shape 60-step chains fuse into one
+# XLA:CPU loop kernel, whose emitter splits it into subgraph functions
+# that call each other once per use: past ~45 steps each step multiplied
+# the run time by 7-23, and the sharded refresh score never returned. A
+# `fori_loop` step is a kernel of its own, so nothing fuses across steps.
 @functools.partial(jax.jit, static_argnames=("cfg", "workloads", "trfc_ns"))
 def _ipc_stack_refresh(
     flat: Array,

@@ -22,13 +22,28 @@ the directory at an actions/cache-restored path; local example::
 Measured on the full tier-1 suite (CPU run, one container): cold 348 s
 (populating ~8.5k cache entries), warm re-run 216 s. The cache skips the
 XLA backend compile; tracing, which it cannot skip, is most of the rest.
+
+Per-test time limit: every test runs under :class:`_time_limit.TimeLimit`,
+so a hung test fails on its own and the rest of the run still ends.
 """
 
 import os
 
 import pytest
 
+from _time_limit import TimeLimit
+
 os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
+
+#: Wall-clock limit per test (setup + call + teardown). The slowest tests
+#: are compile-bound parity gates of up to ~86 s on a shared 8-core CPU
+#: box under 6 xdist workers; 300 s is ~3.5x that, and well inside the
+#: whole run's budget, so only a hang reaches it.
+TEST_TIME_LIMIT_S = 300.0
+
+
+def pytest_configure(config):
+    config.pluginmanager.register(TimeLimit(TEST_TIME_LIMIT_S), "time-limit")
 
 
 @pytest.fixture(autouse=True)
