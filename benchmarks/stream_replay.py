@@ -30,7 +30,8 @@ Parity gates (the run fails hard, CI goes red — never just logs):
 * full scale (where materialized replay cannot run): two different
   chunkings of the same stream — the scan carry is the only state, so
   re-chunking must reproduce state, partials and score bit-exactly
-  (``--impl pallas`` runs the whole thing through the fused kernel).
+  (the chunk scan is chosen by platform, the fused kernel on TPU and the
+  ref elsewhere; ``--impl`` forces one).
 * ``--sharded``: the same gates with the DIMM axis shard_map-ped over
   every visible device; the streamed sharded score must match the
   materialized sharded score bitwise (they share the accumulate/finalize
@@ -47,6 +48,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional
 
 try:
     from benchmarks._sharded_env import ensure_host_devices
@@ -378,11 +380,14 @@ def _sharded_section(table, trace, errors, chunk, score_single):
 def run_full(n_dimms: int = 1_000_000, n_steps: int = 1440,
              chunk: int = 96, error_rate: float = 1e-5,
              dt_s: float = traces.DEFAULT_DT_S, seed: int = 0,
-             sharded: bool = False, verbose: bool = True, impl: str = "ref"):
+             sharded: bool = False, verbose: bool = True,
+             impl: Optional[str] = None):
     """The north-star point: a fleet × trace length whose materialized
     replay history cannot exist on a device. Telemetry is generated
     chunkwise, streamed once (timed), then re-streamed under a different
-    chunking — the ==0 gate that scoring is chunking-invariant."""
+    chunking — the ==0 gate that scoring is chunking-invariant. The chunk
+    scan is chosen by platform unless ``impl`` names one."""
+    impl = stream.resolve_impl(impl)
     key = jax.random.PRNGKey(seed)
     if verbose:
         print(f"# profiling {n_dimms:,} DIMMs ...", flush=True)
@@ -495,9 +500,10 @@ def main() -> None:
                     help="shard the DIMM axis over all visible devices (on "
                          "CPU forces 8 host devices unless XLA_FLAGS pins "
                          "a count) and gate sharded parity")
-    ap.add_argument("--impl", default="ref", choices=("ref", "pallas"),
-                    help="chunk-scan impl for the full-scale run (the tiny "
-                         "kernel section always times both)")
+    ap.add_argument("--impl", default=None, choices=("ref", "pallas"),
+                    help="chunk-scan impl for the full-scale run (default: "
+                         "chosen by platform, pallas on TPU, ref elsewhere; "
+                         "the tiny kernel section always times both)")
     ap.add_argument("--chunk-sweep", type=str, default=None,
                     help="comma list of step-tile sizes to time both impls "
                          "at (tiny mode), e.g. 24,96,512")

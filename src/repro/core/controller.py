@@ -711,15 +711,16 @@ def replay(
 
 
 def replay_stream(table, traces, errors=None, params=ControllerParams(),
-                  state=None, chunk_steps=None, mesh=None, impl="ref",
+                  state=None, chunk_steps=None, mesh=None, impl=None,
                   interpret=None):
     """Streamed (chunked-scan) replay: same state machine, O(n_dimms ·
     chunk) device memory, no materialized history. Lazy delegate to
     :func:`repro.core.stream.replay_stream` (stream imports this module,
     so the import cannot be top-level); see there for the full contract —
     final state, switch counts and score are bit-exact vs :func:`replay`
-    + ``trace_score`` for every chunking, and ``impl="pallas"`` runs each
-    chunk through the fused replay-step kernel (also bit-exact)."""
+    + ``trace_score`` for every chunking, and the chunk scan is chosen by
+    platform (the fused replay-step kernel on TPU, also bit-exact; the
+    ref elsewhere) unless ``impl`` names one."""
     from repro.core import stream as _stream
 
     kwargs = {} if chunk_steps is None else {"chunk_steps": chunk_steps}
@@ -850,14 +851,15 @@ class ALDRAMController:
         return result
 
     def replay_stream(self, traces, errors=None, chunk_steps=None, mesh=None,
-                      impl="ref", interpret=None):
+                      impl=None, interpret=None):
         """Advance this controller over a temperature STREAM in chunked
         scans — identical state/counter absorption to :meth:`replay`
         (property-tested equal), but O(n_dimms · chunk) device memory and
         no materialized history: ``traces`` may be a ``(n_steps,
         n_dimms)`` array or any iterable of ``(temps_chunk, errors_chunk)``
-        pairs longer than memory allows. ``impl="pallas"`` fuses each
-        chunk scan into the replay-step kernel (bit-exact). Returns a
+        pairs longer than memory allows. The chunk scan is chosen by
+        platform unless ``impl`` names one (the fused replay-step kernel
+        on TPU, bit-exact; the ref elsewhere). Returns a
         :class:`repro.core.stream.StreamResult` (``.score()`` gives the
         bit-exact ``trace_score`` dict)."""
         result = replay_stream(

@@ -39,12 +39,15 @@ state machine:
   service (:mod:`repro.launch.serve_fleet`): ``ingest`` batched
   observation chunks (optionally returning the realized timings / bin
   decisions for programming hardware), ``score`` the stream so far.
-* **Fused kernel path**: ``impl="pallas"`` swaps each chunk scan for the
-  fused replay-step kernel (:mod:`repro.kernels.replay_step`) — step +
-  timing lookup + partials accumulation in one VMEM-resident pass per
-  DIMM tile, bit-exact vs the ref scan (same adds, same order). The
-  chunk-scan *semantics* live in :mod:`repro.kernels.replay_step.ref`;
-  this module aliases them.
+* **Implementation chosen by platform**: on a TPU backend every
+  non-emitting chunk runs the fused replay-step kernel
+  (:mod:`repro.kernels.replay_step`) — step + timing lookup + partials
+  accumulation in one VMEM-resident pass per DIMM tile, bit-exact vs the
+  ref scan (same adds, same order); elsewhere, where the kernel would
+  only run in the Pallas interpreter, the ref ``lax.scan``. An explicit
+  ``impl="ref"|"pallas"`` overrides the choice. The chunk-scan
+  *semantics* live in :mod:`repro.kernels.replay_step.ref`; this module
+  aliases them.
 
 Chunk-size guidance: every distinct chunk length compiles its own scan,
 so feed uniform chunks (one trailing ragged chunk costs exactly one extra
@@ -136,22 +139,33 @@ def _sharded_chunk_runner(mesh, n_dimms: int, emit: bool, impl: str = "ref",
     return shard.sharded_dimm_map(fn, mesh, in_axes, out_axes, n_dimms)
 
 
+def resolve_impl(impl: Optional[str]) -> str:
+    """The chunk-scan implementation ``impl`` names, or for ``None`` the
+    platform's: the fused kernel on a TPU backend, the ref scan
+    elsewhere (off-TPU the kernel only runs in the Pallas interpreter)."""
+    if impl is None:
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
+    if impl not in replay_ops.IMPLS:
+        raise ValueError(
+            f"impl must be one of {replay_ops.IMPLS}, got {impl!r}"
+        )
+    return impl
+
+
 def _chunk_runner(mesh, n_dimms: int, temp_bins, params: ControllerParams,
-                  emit: bool = False, impl: str = "ref",
+                  emit: bool = False, impl: Optional[str] = None,
                   interpret: Optional[bool] = None):
     """THE dispatch point for every chunk-scan call site (replay_stream
     and StreamingController.ingest both route here).
 
     ``impl="pallas"`` selects the fused replay-step kernel
     (:mod:`repro.kernels.replay_step`) — bit-exact vs the ref by the
-    kernel's accumulation-order contract. The decision-EMITTING path
-    stays on the ref: materializing the per-step rows is precisely what
-    the kernel exists to avoid, and the partials it carries are
-    bit-identical either way."""
-    if impl not in replay_ops.IMPLS:
-        raise ValueError(
-            f"impl must be one of {replay_ops.IMPLS}, got {impl!r}"
-        )
+    kernel's accumulation-order contract; ``impl=None`` chooses by
+    platform (:func:`resolve_impl`). The decision-EMITTING path stays
+    on the ref: materializing the per-step rows is precisely what the
+    kernel exists to avoid, and the partials it carries are bit-identical
+    either way."""
+    impl = resolve_impl(impl)
     if emit or impl == "ref":
         fn, key = (_chunk_scan_emit if emit else _chunk_scan), None
         impl = "ref"
@@ -342,7 +356,7 @@ def replay_stream(
     state: Optional[ControllerState] = None,
     chunk_steps: int = DEFAULT_CHUNK_STEPS,
     mesh=None,
-    impl: str = "ref",
+    impl: Optional[str] = None,
     interpret: Optional[bool] = None,
     region_mix: Optional[Array] = None,
 ) -> StreamResult:
@@ -369,12 +383,13 @@ def replay_stream(
     chunks are device_put pre-sharded (double-buffered against the
     in-flight scan).
 
-    ``impl`` — ``"ref"`` (jitted scan of separate XLA ops) or
-    ``"pallas"`` (the fused replay-step kernel,
-    :mod:`repro.kernels.replay_step`: step + timing lookup + partials in
-    one VMEM-resident pass, bit-exact vs the ref). ``interpret=None``
-    auto-enables kernel interpret mode off-TPU. Under a mesh the kernel
-    runs locally per shard.
+    ``impl`` — chosen by platform when ``None``: the fused replay-step
+    kernel (:mod:`repro.kernels.replay_step`: step + timing lookup +
+    partials in one VMEM-resident pass, bit-exact vs the ref) on TPU,
+    the ref (jitted scan of separate XLA ops) elsewhere. ``"ref"`` or
+    ``"pallas"`` forces one. ``interpret=None`` auto-enables kernel
+    interpret mode off-TPU. Under a mesh the kernel runs locally per
+    shard.
 
     ``region_mix`` — optional ``(n_steps, n_dimms, n_regions)`` int32
     per-step region-access counts (region tables, schema v5): each chunk
@@ -385,14 +400,15 @@ def replay_stream(
     :meth:`StreamResult.region_score`. Integer accumulation keeps
     streamed counts bitwise-equal to a materialized accumulation at
     every chunking and same-mesh sharding. Requires a materialized
-    ``traces`` array and stays on the ref scan (the precedent of the
-    decision-emitting path); the carried :class:`ScorePartials` are
-    bit-identical to a mix-free stream of the same trace."""
+    ``traces`` array and stays on the ref scan on every platform (the
+    precedent of the decision-emitting path); the carried
+    :class:`ScorePartials` are bit-identical to a mix-free stream of the
+    same trace."""
     if state is None:
         state = init_state(table.n_dimms, table.n_bins)
     region_counts = None
     if region_mix is not None:
-        if impl != "ref":
+        if impl not in (None, "ref"):
             raise ValueError(
                 "region_mix streaming runs the ref chunk scan; drop "
                 f"impl={impl!r}"
@@ -522,10 +538,13 @@ class StreamingController:
     :meth:`~repro.core.controller.ALDRAMController.replay` — the two
     wrappers are interchangeable step for step.
 
-    ``impl="pallas"`` runs every non-decision-emitting chunk through the
-    fused replay-step kernel (bit-exact vs ``"ref"``);
-    ``return_decisions=True`` chunks always take the ref scan, which is
-    safe to mix freely — the carried partials are bit-identical."""
+    The chunk-scan implementation is chosen by platform (``impl=None``):
+    on TPU every non-decision-emitting chunk runs the fused replay-step
+    kernel (bit-exact vs ``"ref"``), elsewhere the ref scan;
+    ``impl="ref"|"pallas"`` forces one, and :attr:`impl` reports the
+    one chosen. ``return_decisions=True`` chunks always take the ref
+    scan, which is safe to mix freely — the carried partials are
+    bit-identical."""
 
     def __init__(
         self,
@@ -533,17 +552,13 @@ class StreamingController:
         params: ControllerParams = ControllerParams(),
         state: Optional[ControllerState] = None,
         mesh=None,
-        impl: str = "ref",
+        impl: Optional[str] = None,
         interpret: Optional[bool] = None,
     ):
-        if impl not in replay_ops.IMPLS:
-            raise ValueError(
-                f"impl must be one of {replay_ops.IMPLS}, got {impl!r}"
-            )
         self.table = table
         self.params = params
         self.mesh = mesh
-        self.impl = impl
+        self.impl = resolve_impl(impl)
         self.interpret = interpret
         self._stack = jnp.asarray(table.oblivious_stack())
         self._edges = jnp.asarray(table.temp_bins, jnp.float32)

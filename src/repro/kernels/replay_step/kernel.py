@@ -43,20 +43,23 @@ tiles; every per-DIMM operand arrives stacked on a leading axis —
 state as (3, 8, 128) int32 [bin, streak, fused], occupancy as
 (n_bins+1, 8, 128), timing sums and each bin's (2, 4) block flattened to
 8 slots. The step axis walks a ``fori_loop`` whose carry is the full
-register set; the grid walks DIMM tiles.
+register set; the grid walks DIMM tiles and, within each, blocks of
+steps in order.
 
 VMEM budget: every block is a whole number of 4 KiB (8, 128) tiles, and
 the Pallas pipeline double-buffers each input and output block. The
-chunk kernel's blocks per 1024-DIMM tile are ``3 + (n_bins+1) + 1 + 8 +
-8·n_bins + 2·chunk`` in and ``3 + (n_bins+1) + 1 + 8`` out, so it holds
-``2 · (10·n_bins + 2·chunk + 26) · 4 KiB`` — 4.6 MiB at 5 bins and
-chunk 256, inside the 16 MiB scoped VMEM limit (the telemetry block
-grows with ``chunk`` and reaches the limit near chunk 980). The accumulate kernel
-streams its decision block :data:`ACC_STEP_BLOCK` steps at a time, so it
-holds ``2 · (10·steps + 2·(n_bins+1) + 18) · 4 KiB`` — 2.7 MiB at 32
-steps and 5 bins, whatever the chunk length. Taking the whole chunk as
-one block would need 20.2 MiB at chunk 256. (8, 128) is the f32 VPU
-register shape and stays fixed.
+chunk kernel streams the telemetry :data:`CHUNK_STEP_BLOCK` steps at a
+time (grid axis 1) while the registers and partials stay resident in its
+outputs, so its blocks per 1024-DIMM tile are ``3 + (n_bins+1) + 1 + 8 +
+8·n_bins + 2·steps`` in and ``3 + (n_bins+1) + 1 + 8`` out, and it holds
+``2 · (10·n_bins + 2·steps + 26) · 4 KiB`` — 4.6 MiB at 5 bins and 256
+steps, inside the 16 MiB scoped VMEM limit whatever the chunk length
+(taking a whole chunk as one block reaches the limit near chunk 980).
+The accumulate kernel streams its decision block :data:`ACC_STEP_BLOCK`
+steps at a time, so it holds ``2 · (10·steps + 2·(n_bins+1) + 18) · 4
+KiB`` — 2.7 MiB at 32 steps and 5 bins, whatever the chunk length.
+Taking the whole chunk as one block would need 20.2 MiB at chunk 256.
+(8, 128) is the f32 VPU register shape and stays fixed.
 """
 
 from __future__ import annotations
@@ -101,16 +104,20 @@ def _replay_chunk_kernel(
     sw_ref,      # (8, 128) i32
     sums_ref,    # (ROW_SLOTS, 8, 128) f32
     stack_ref,   # (n_bins · ROW_SLOTS, 8, 128) f32
-    temps_ref,   # (chunk, 8, 128) f32
-    errs_ref,    # (chunk, 8, 128) f32 (0.0 / 1.0)
+    temps_ref,   # (steps, 8, 128) f32
+    errs_ref,    # (steps, 8, 128) f32 (0.0 / 1.0)
     state_out,   # (3, 8, 128) i32
     occ_out,     # (n_bins+1, 8, 128) i32
     sw_out,      # (8, 128) i32
     sums_out,    # (ROW_SLOTS, 8, 128) f32
     *,
-    chunk: int,
+    steps: int,
     scal: ReplayScalars,
 ):
+    """One block of ``steps`` steps for one DIMM tile. The outputs stay
+    resident across the step-block grid axis and carry the registers and
+    partials from one block to the next, so every slot still takes one
+    f32 add per step, in step order."""
     n_bins = len(scal.edges)
     guard = jnp.float32(scal.guard_band_c)
     hyst = jnp.float32(scal.hysteresis_c)
@@ -167,16 +174,23 @@ def _replay_chunk_kernel(
         return (new_bin, new_streak, fused.astype(jnp.int32), sw, occ,
                 tuple(new_sums))
 
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state_out[...] = state_ref[...]
+        occ_out[...] = occ_ref[...]
+        sw_out[...] = sw_ref[...]
+        sums_out[...] = sums_ref[...]
+
     init = (
-        state_ref[0],
-        state_ref[1],
-        state_ref[2],
-        sw_ref[...],
-        tuple(occ_ref[b] for b in range(n_bins + 1)),
-        tuple(sums_ref[j] for j in range(ROW_SLOTS)),
+        state_out[0],
+        state_out[1],
+        state_out[2],
+        sw_out[...],
+        tuple(occ_out[b] for b in range(n_bins + 1)),
+        tuple(sums_out[j] for j in range(ROW_SLOTS)),
     )
     bin_idx, streak, fused, sw, occ, sums = jax.lax.fori_loop(
-        0, chunk, one_step, init
+        0, steps, one_step, init
     )
     state_out[0] = bin_idx
     state_out[1] = streak
@@ -186,6 +200,60 @@ def _replay_chunk_kernel(
     sw_out[...] = sw
     for j in range(ROW_SLOTS):
         sums_out[j] = sums[j]
+
+
+#: Steps per chunk-kernel block. The telemetry streams through VMEM
+#: ``CHUNK_STEP_BLOCK`` steps at a time (grid axis 1) while the registers
+#: and partials stay resident, so the kernel's VMEM use does not grow with
+#: the chunk length.
+CHUNK_STEP_BLOCK: int = 256
+
+
+def _replay_blocks(state3, occ, sw, sums, stack, temps, errs, *,
+                   steps: int, scal: ReplayScalars, interpret: bool):
+    """One ``pallas_call`` over every DIMM tile and the first
+    ``len(temps) // steps`` blocks of ``steps`` steps."""
+    n_bins = len(scal.edges)
+    rows_, lanes = sw.shape
+    n_blocks = temps.shape[0] // steps
+
+    def stacked_spec(n):
+        return pl.BlockSpec((n, TILE[0], TILE[1]), lambda i, k: (0, i, 0))
+
+    def step_spec(n):
+        return pl.BlockSpec((n, TILE[0], TILE[1]), lambda i, k: (k, i, 0))
+
+    flat_spec = pl.BlockSpec((TILE[0], TILE[1]), lambda i, k: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_replay_chunk_kernel, steps=steps, scal=scal),
+        grid=(rows_ // TILE[0], n_blocks),
+        in_specs=[
+            stacked_spec(3),
+            stacked_spec(n_bins + 1),
+            flat_spec,
+            stacked_spec(ROW_SLOTS),
+            stacked_spec(n_bins * ROW_SLOTS),
+            step_spec(steps),
+            step_spec(steps),
+        ],
+        out_specs=(
+            stacked_spec(3),
+            stacked_spec(n_bins + 1),
+            flat_spec,
+            stacked_spec(ROW_SLOTS),
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((3, rows_, lanes), jnp.int32),
+            jax.ShapeDtypeStruct((n_bins + 1, rows_, lanes), jnp.int32),
+            jax.ShapeDtypeStruct((rows_, lanes), jnp.int32),
+            jax.ShapeDtypeStruct((ROW_SLOTS, rows_, lanes), jnp.float32),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        name="replay_chunk",
+    )(state3, occ, sw, sums, stack, temps, errs)
 
 
 def replay_chunk_tiled(
@@ -203,7 +271,11 @@ def replay_chunk_tiled(
     """Run the fused chunk scan over tiled DIMM operands.
 
     R % 8 == 0 (ops pads/reshapes the DIMM axis). Returns
-    ``(state3, occ, sw, sums)`` with input shapes/dtypes."""
+    ``(state3, occ, sw, sums)`` with input shapes/dtypes. The whole
+    :data:`CHUNK_STEP_BLOCK`-step blocks of the chunk run in one kernel
+    launch; a remainder shorter than a block runs in a second launch that
+    starts from the first one's registers and partials, so every step
+    still runs once, in step order."""
     n_bins = len(scal.edges)
     rows_, lanes = sw.shape
     chunk = temps.shape[0]
@@ -214,36 +286,15 @@ def replay_chunk_tiled(
     assert stack.shape == (n_bins * ROW_SLOTS, rows_, lanes), stack.shape
     assert temps.shape == errs.shape == (chunk, rows_, lanes), temps.shape
 
-    def stacked_spec(n):
-        return pl.BlockSpec((n, TILE[0], TILE[1]), lambda i: (0, i, 0))
-
-    flat_spec = pl.BlockSpec((TILE[0], TILE[1]), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_replay_chunk_kernel, chunk=chunk, scal=scal),
-        grid=(rows_ // TILE[0],),
-        in_specs=[
-            stacked_spec(3),
-            stacked_spec(n_bins + 1),
-            flat_spec,
-            stacked_spec(ROW_SLOTS),
-            stacked_spec(n_bins * ROW_SLOTS),
-            stacked_spec(chunk),
-            stacked_spec(chunk),
-        ],
-        out_specs=(
-            stacked_spec(3),
-            stacked_spec(n_bins + 1),
-            flat_spec,
-            stacked_spec(ROW_SLOTS),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((3, rows_, lanes), jnp.int32),
-            jax.ShapeDtypeStruct((n_bins + 1, rows_, lanes), jnp.int32),
-            jax.ShapeDtypeStruct((rows_, lanes), jnp.int32),
-            jax.ShapeDtypeStruct((ROW_SLOTS, rows_, lanes), jnp.float32),
-        ),
-        interpret=interpret,
-    )(state3, occ, sw, sums, stack, temps, errs)
+    steps = min(chunk, CHUNK_STEP_BLOCK)
+    out = _replay_blocks(state3, occ, sw, sums, stack, temps, errs,
+                         steps=steps, scal=scal, interpret=interpret)
+    rest = chunk % steps
+    if rest:
+        out = _replay_blocks(*out, stack, temps[chunk - rest:],
+                             errs[chunk - rest:], steps=rest, scal=scal,
+                             interpret=interpret)
+    return out
 
 
 #: Steps per accumulate-kernel block. The decision block streams through

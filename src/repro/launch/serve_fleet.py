@@ -12,15 +12,16 @@ only O(n_dimms) state + score partials no matter how long it runs, every
 chunk is one jitted scan (double-buffered host→device ingestion), and the
 running score is bit-exact vs materializing the whole history. Composes
 with the ``"dimm"`` device mesh (:mod:`repro.core.shard`) for fleets
-bigger than one device, and with ``impl="pallas"`` for the fused
-replay-step kernel (:mod:`repro.kernels.replay_step`): non-decision
-chunks then run step + timing lookup + score accumulation in one
-VMEM-resident kernel pass, bit-exact vs the ref scan.
+bigger than one device. The chunk scan is chosen by platform: on TPU,
+non-decision chunks run the fused replay-step kernel
+(:mod:`repro.kernels.replay_step`: step + timing lookup + score
+accumulation in one VMEM-resident pass, bit-exact vs the ref scan);
+elsewhere, and for every decision chunk, the ref scan. ``impl=`` /
+``--impl`` forces one.
 
 Usage (demo driver feeding a synthetic scenario through the service):
   PYTHONPATH=src python -m repro.launch.serve_fleet \
-      --n-dimms 512 --n-steps 1440 --chunk 256 --scenario diurnal \
-      --impl pallas
+      --n-dimms 512 --n-steps 1440 --chunk 256 --scenario diurnal
 """
 
 from __future__ import annotations
@@ -51,15 +52,17 @@ class FleetControllerService:
     the JEDEC fallback sentinel) and the switch flags, which is exactly
     what a hardware-programming agent consumes. :meth:`running_score`
     finalizes the accumulated partials at any time without disturbing the
-    stream. ``interpret`` passes through to the fused kernel
-    (``None``: interpret mode everywhere but TPU)."""
+    stream. ``impl=None`` chooses the chunk scan by platform (the fused
+    kernel on TPU, the ref elsewhere; ``self.engine.impl`` names it).
+    ``interpret`` passes through to the fused kernel (``None``:
+    interpret mode everywhere but TPU)."""
 
     def __init__(
         self,
         table: DimmTimingTable,
         params: ControllerParams = ControllerParams(),
         mesh=None,
-        impl: str = "ref",
+        impl: Optional[str] = None,
         interpret: Optional[bool] = None,
     ):
         self.engine = stream.StreamingController(
@@ -109,7 +112,7 @@ def serve(
     sharded: bool = False,
     seed: int = 0,
     table: Optional[DimmTimingTable] = None,
-    impl: str = "ref",
+    impl: Optional[str] = None,
 ) -> Dict[str, float]:
     """Demo driver: boot the service, stream a synthetic scenario through
     it chunk by chunk, report throughput + the running score."""
@@ -142,7 +145,8 @@ def serve(
     realtime = n_steps * dt_s / max(wall, 1e-9)
     print(
         f"[serve_fleet] {scenario}: {n_dimms} DIMMs × {n_steps} steps "
-        f"(chunk {chunk}, impl {impl}{', sharded' if sharded else ''}"
+        f"(chunk {chunk}, impl {service.engine.impl}"
+        f"{', sharded' if sharded else ''}"
         f"{', decisions' if decisions else ''}) | "
         f"{resp.get('n_chunks', 0)} chunks in {wall:.2f} s "
         f"({n_steps * n_dimms / max(wall, 1e-9):,.0f} obs/s, "
@@ -183,8 +187,10 @@ def main() -> None:
                     help="return per-chunk timing rows / bin decisions")
     ap.add_argument("--sharded", action="store_true",
                     help="shard the DIMM axis over the fleet mesh")
-    ap.add_argument("--impl", default="ref", choices=("ref", "pallas"),
-                    help="chunk-scan implementation (pallas = fused kernel)")
+    ap.add_argument("--impl", default=None, choices=("ref", "pallas"),
+                    help="chunk-scan implementation (pallas = fused kernel); "
+                         "default: chosen by platform, pallas on TPU, ref "
+                         "elsewhere")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     enable_compile_cache()

@@ -1,7 +1,8 @@
 """Compile the fleet controller's main path for a described TPU v5e chip.
 
 Nothing runs. Each test compiles at the size ``chip_smoke.py`` runs
-(10⁶ DIMMs, 5 temperature bins, chunk 256) for one chip of a ``v5e:2x2``
+(10⁶ DIMMs, 5 temperature bins, chunk 256; the chunk kernel also at a
+day's 1,440 steps) for one chip of a ``v5e:2x2``
 topology described here, so a kernel that Mosaic refuses, or one that
 overruns VMEM or the chip's memory, fails on a machine without a chip.
 The topology is described inside a fixture, never while a module is
@@ -35,6 +36,8 @@ from repro.kernels.replay_step.ops import replay_scalars
 N_DIMMS = 1_000_000
 N_BINS = len(controller.DEFAULT_TEMP_BINS)
 CHUNK = 256
+#: Steps of one day of telemetry at minute cadence.
+DAY_STEPS = 1440
 #: HBM of one TPU v5e chip (Google Cloud documentation, "TPU v5e").
 V5E_HBM_BYTES = 16 * 10**9
 #: Lane rows of a DIMM-axis operand padded to whole 1,024-DIMM tiles.
@@ -94,21 +97,30 @@ def test_charge_sweep_kernel_compiles_at_fleet_size(compile_for_chip):
     assert _has_kernel(compiled)
 
 
-def test_replay_chunk_kernel_compiles_at_fleet_size(compile_for_chip):
+def _compile_replay_chunk(compile_for_chip, chunk):
     scal = replay_scalars(controller.DEFAULT_TEMP_BINS,
                           controller.ControllerParams())
     i32, f32 = jnp.int32, jnp.float32
-    compiled = compile_for_chip(
+    return compile_for_chip(
         lambda *a: replay_chunk_tiled(*a, scal=scal),
         ((3, ROWS, 128), i32),
         ((N_BINS + 1, ROWS, 128), i32),
         ((ROWS, 128), i32),
         ((ROW_SLOTS, ROWS, 128), f32),
         ((N_BINS * ROW_SLOTS, ROWS, 128), f32),
-        ((CHUNK, ROWS, 128), f32),
-        ((CHUNK, ROWS, 128), f32),
+        ((chunk, ROWS, 128), f32),
+        ((chunk, ROWS, 128), f32),
     )
-    assert _has_kernel(compiled)
+
+
+def test_replay_chunk_kernel_compiles_at_fleet_size(compile_for_chip):
+    assert _has_kernel(_compile_replay_chunk(compile_for_chip, CHUNK))
+
+
+def test_replay_chunk_kernel_compiles_for_a_day_long_chunk(compile_for_chip):
+    """A whole day at minute cadence in one chunk, past the length at
+    which a whole-chunk telemetry block would overrun scoped VMEM."""
+    assert _has_kernel(_compile_replay_chunk(compile_for_chip, DAY_STEPS))
 
 
 def test_accumulate_kernel_compiles_at_fleet_size(compile_for_chip):

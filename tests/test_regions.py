@@ -244,6 +244,26 @@ def test_streamed_region_score_bitwise_vs_materialized(chunk_steps):
     assert out.region_score() == score
 
 
+def test_streamed_region_mix_stays_on_ref_on_tpu(monkeypatch):
+    """The region-resolved scan has no kernel: on a TPU backend a stream
+    with a region mix still runs the ref scan (here on the CPU, where a
+    compiled TPU kernel could not run), and only an explicit
+    ``impl="pallas"`` is refused."""
+    score, table, tr, mix = _scored("hot_bank")
+    want = replay_stream(table, tr, chunk_steps=32, region_mix=mix,
+                         impl="ref")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    out = replay_stream(table, tr, chunk_steps=32, region_mix=mix)
+    np.testing.assert_array_equal(np.asarray(out.region_counts),
+                                  np.asarray(want.region_counts))
+    for a, b in zip(out.partials, want.partials):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert out.region_score() == score
+    with pytest.raises(ValueError, match="region_mix"):
+        replay_stream(table, tr, chunk_steps=32, region_mix=mix,
+                      impl="pallas")
+
+
 def test_stream_without_mix_has_no_region_counts():
     table = region_table(n_regions=2)
     tr = traces.generate("diurnal", KEY, 4, 32)

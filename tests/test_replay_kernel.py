@@ -20,7 +20,9 @@ cycle-quantization envelope:
   edges, guard-band and hysteresis-margin corners) where one misrounded
   comparison would flip a transition;
 * the decision-EMITTING serving path stays on the ref and mixes freely
-  with fused chunks (the carried partials are bit-identical).
+  with fused chunks (the carried partials are bit-identical);
+* with no ``impl`` given, the chunk scan is chosen by platform: the
+  kernel on TPU, the ref elsewhere.
 
 Runs tier-1 on one device in interpret mode (the same kernel body that
 compiles for TPU); the CI multidevice job re-runs this module on an
@@ -36,6 +38,9 @@ import pytest
 from _hypothesis_compat import given, settings, st
 from repro.core import controller, fleet, perfmodel, shard, stream, traces
 from repro.kernels.replay_step import ops as replay_ops
+from repro.kernels.replay_step import ref as replay_ref
+from repro.kernels.replay_step.kernel import CHUNK_STEP_BLOCK
+from repro.launch.serve_fleet import FleetControllerService
 
 TEMPS = (45.0, 55.0, 85.0)
 N_MAX = 11
@@ -130,6 +135,27 @@ def test_pallas_stream_partials_bitwise_vs_ref(n, error_rate):
                              impl="pallas")
     _assert_state_equal(p.state, r.state)
     _assert_partials_equal(p.partials, r.partials)
+
+
+@pytest.mark.parametrize("chunk", [2 * CHUNK_STEP_BLOCK, 600, 1440])
+def test_pallas_chunk_longer_than_a_step_block(chunk):
+    """A chunk longer than one :data:`CHUNK_STEP_BLOCK` streams through
+    the kernel block by block (whole blocks, then a shorter remainder for
+    600 and 1440, a day at minute cadence): state and partials still equal
+    the ref chunk scan's bit for bit, and the materialized replay's."""
+    n = N_MAX
+    table = _sub_table(n)
+    k_t, k_e = jax.random.split(jax.random.PRNGKey(chunk))
+    trace = np.asarray(traces.generate("diurnal", k_t, n, chunk))
+    errors = np.asarray(traces.error_injections(k_e, chunk, n, 0.002))
+    r = stream.replay_stream(table, trace, errors, chunk_steps=chunk,
+                             impl="ref")
+    p = stream.replay_stream(table, trace, errors, chunk_steps=chunk,
+                             impl="pallas")
+    _assert_state_equal(p.state, r.state)
+    _assert_partials_equal(p.partials, r.partials)
+    _assert_state_equal(p.state, controller.replay(table, trace, errors).state)
+    assert p.n_steps == chunk
 
 
 # ---------------------------------------------------------------------------
@@ -267,6 +293,65 @@ def test_streaming_controller_pallas_mixed_emit():
     assert eng.score() == score_ref
     _assert_state_equal(eng.state, ref.state)
     assert eng.total_switches == ref.total_switches
+
+
+# ---------------------------------------------------------------------------
+# The chunk scan is chosen by platform
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize(
+    "backend, impl, emit, sharded, want",
+    [
+        ("tpu", None, False, False, "kernel"),
+        ("cpu", None, False, False, "ref"),
+        ("gpu", None, False, False, "ref"),
+        ("tpu", None, True, False, "ref"),
+        ("tpu", "ref", False, False, "ref"),
+        ("cpu", "pallas", False, False, "kernel"),
+        ("cpu", "pallas", True, False, "ref"),
+        ("tpu", None, False, True, "kernel"),
+        ("tpu", None, True, True, "ref"),
+        ("cpu", None, False, True, "ref"),
+    ],
+)
+def test_chunk_runner_chooses_by_platform(monkeypatch, backend, impl, emit,
+                                          sharded, want):
+    """``impl=None`` resolves from ``jax.default_backend()``: the fused
+    kernel, compiled (not interpreted), on TPU; the ref scan elsewhere.
+    The decision-emitting scan stays on the ref on every platform, an
+    explicit ``impl`` overrides the platform, and under a mesh the same
+    choice is made for each shard."""
+    table = _table_full()
+    params = controller.ControllerParams()
+    mesh = _mesh() if sharded else None
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    got = stream._chunk_runner(mesh, table.n_dimms, table.temp_bins, params,
+                               emit=emit, impl=impl)
+    if want == "kernel":
+        key = (tuple(table.temp_bins), replay_ops.canonical_params(params),
+               backend != "tpu")
+        fn = replay_ops.pallas_chunk_scan(*key)
+    else:
+        key = None
+        fn = replay_ref.chunk_scan_emit if emit else replay_ref.chunk_scan
+    if sharded:
+        impl_used = "pallas" if want == "kernel" else "ref"
+        fn = stream._sharded_chunk_runner(mesh, table.n_dimms, emit,
+                                          impl_used, key)
+    assert got is fn
+
+
+@pytest.mark.parametrize(
+    "backend, impl, want",
+    [("tpu", None, "pallas"), ("cpu", None, "ref"), ("tpu", "ref", "ref"),
+     ("cpu", "pallas", "pallas")],
+)
+def test_engine_reports_chosen_impl(monkeypatch, backend, impl, want):
+    """The streaming engine, and the service over it, name the chunk scan
+    they run, never ``None``."""
+    table = _sub_table(3)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert stream.StreamingController(table, impl=impl).impl == want
+    assert FleetControllerService(table, impl=impl).engine.impl == want
 
 
 # ---------------------------------------------------------------------------
