@@ -46,11 +46,15 @@ T_WORST = 85.0
 EXT_BOUNDARY = 85.0
 EXT_FACTOR = 0.5
 EPS = 1e-4
-#: The reference's own rounding: a threshold moved by this share (about
-#: eight float32 ulps) gives the band of answers float32 arithmetic can
-#: give at a cell that sits on it. Answers inside the band are the
-#: reference's; see :func:`table`.
-SLACK = 1e-6
+#: The reference's own rounding: a threshold moved by this share gives the
+#: band of answers float32 arithmetic can give at a cell that sits on it.
+#: Answers inside the band are the reference's; see :func:`table`. On the
+#: TPU, whose float32 log errs by up to about 1e-4, this reference and the
+#: program part at cells up to about 2e-6 of a threshold apart (one
+#: register in 1.6e9 over 40 seeds of warehouse-1m past 1e-6, none past
+#: 2e-6), so the band is five times that; the bfloat16 control still puts
+#: about 2.4 million of a fleet's 40 million registers outside it.
+SLACK = 1e-5
 RET85 = 0.9282
 LEAK_DOUBLING = 7.24
 V_FULL = 0.975
