@@ -349,6 +349,11 @@ class Driver:
             self.kept_tables.clear()
         if self.unit["submits"]:
             self.submit(sink)
+            if self.mesh is not None:
+                # The first chunk finds the service's fresh state on one
+                # device, every later one finds it sharded over the mesh:
+                # a second program.
+                self.submit(sink)
         if self.unit["scores"]:
             self.score(sink)
         self.kept_decisions.clear()

@@ -51,3 +51,27 @@ def test_idle_gaps_by_span():
 def test_top_ops():
     t = synthetic()
     assert t.top_ops(0, 100)[0] == ("fusion.1", (30 + 20) / 2)
+
+
+def test_submit_idle_ms_per_submit_mean_over_devices():
+    import harness
+
+    ms = 1_000_000
+    ops = {"/device:TPU:0": [("replay_chunk", 10 * ms, 30 * ms),
+                             ("replay_chunk", 70 * ms, 90 * ms),
+                             ("fusion", 115 * ms, 120 * ms)],
+           "/device:TPU:1": [("replay_chunk", 0, 40 * ms),
+                             ("replay_chunk", 60 * ms, 100 * ms)]}
+    spans = [("bench.submit", 0, 50 * ms), ("bench.submit", 60 * ms, 100 * ms),
+             ("bench.running_score", 110 * ms, 130 * ms)]
+    calls = [harness.Call("submit", 0.0, 0.05, 256),
+             harness.Call("submit", 0.06, 0.1, 256),
+             harness.Call("score", 0.11, 0.13)]
+    rec = harness.Run(None, 1000, 5, 2, {}, 1.0, 0.13, calls,
+                      xplane.Trace(ops, spans))
+    read = harness.metric_reader(BENCH, "submit_idle_ms.backfill")
+    # Inside the submits device 0 idles 30 + 20 ms and device 1 10 + 0 ms;
+    # the 10 ms between the spans and the score's idle are not counted.
+    assert read(rec) == pytest.approx((30 + 20 + 10 + 0) / 2 / 2)
+    rec.trace = None
+    assert read(rec) is None
